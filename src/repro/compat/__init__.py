@@ -1,25 +1,23 @@
-"""JAX version-compatibility layer.
+"""JAX API layer.
 
-Every JAX API whose surface drifted across the versions this repo supports
-(0.4.35 – 0.6.x) is adapted exactly once, here, by feature detection at
-import time — source modules import the stable names below and never touch
-the drifting spellings directly.
-
-Policy (documented in CHANGES.md): when an API moves, add the adapter here
-with a feature probe (``hasattr`` / ``TypeError`` fallback, never a version
-string compare), keep the *new* JAX spelling as the canonical argument
-surface, and cover both branches in tests where the installed JAX allows.
+The repo targets the installed JAX (0.9.0, jaxlib 0.9.0, libtpu 0.0.34).
+The APIs below are the ones whose spelling has moved between JAX
+releases; source modules import the stable names here and never touch
+the drifting spellings directly, so the next move is absorbed in one
+place (the ``jax-drift`` lint in :mod:`repro.analysis.lints` enforces
+this).
 
 Stable surface:
-  * :func:`tpu_compiler_params`      — pltpu.CompilerParams / TPUCompilerParams
-  * :func:`make_mesh`                — jax.make_mesh with/without axis_types
-  * :func:`set_mesh`                 — jax.set_mesh / sharding.use_mesh / Mesh ctx
-  * :func:`active_mesh_axis_names`   — abstract mesh / thread-resource env
-  * :func:`mesh_axis_sizes`          — Mesh.axis_sizes / devices.shape
-  * :func:`shard_map`                — jax.shard_map / experimental.shard_map
-  * :func:`normalize_cost_analysis`  — dict vs list[dict] returns
-  * :func:`xla_cost_analysis`        — Compiled -> normalized flat dict
-  * :func:`tree_map`                 — jax.tree.map / jax.tree_util.tree_map
+  * :func:`tpu_compiler_params`      — ``pltpu.CompilerParams``
+  * :func:`make_mesh`                — ``jax.make_mesh`` with Auto axes
+  * :func:`set_mesh`                 — ``jax.set_mesh``
+  * :func:`active_mesh`              — ``jax.sharding.get_abstract_mesh``
+  * :func:`active_mesh_axis_names`   — its axis names
+  * :func:`mesh_axis_sizes`          — ``Mesh.axis_sizes`` by name
+  * :func:`shard_map`                — ``jax.shard_map``
+  * :func:`normalize_cost_analysis`  — ``cost_analysis()`` -> flat dict
+  * :func:`xla_cost_analysis`        — Compiled -> flat dict
+  * :func:`tree_map`                 — ``jax.tree.map``
 """
 from __future__ import annotations
 

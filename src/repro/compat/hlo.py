@@ -1,22 +1,15 @@
-"""Compiled-artifact compatibility: ``Compiled.cost_analysis()`` returned
-``list[dict]`` (one entry per partition/program) through JAX 0.4.x and a
-flat ``dict`` from 0.5.x on. Consumers here always see the flat dict.
-"""
+"""Compiled-artifact cost analysis as a flat ``{metric: value}`` dict."""
 from __future__ import annotations
 
 
 def normalize_cost_analysis(cost) -> dict:
-    """list[dict] | dict | None -> flat {metric: value} dict."""
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
+    """dict | None -> flat {metric: value} dict."""
+    return dict(cost) if cost else {}
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """Normalized cost analysis of a ``jax.stages.Compiled``; {} when the
-    backend provides none."""
+    """Cost analysis of a ``jax.stages.Compiled``; {} when the backend
+    provides none."""
     try:
         cost = compiled.cost_analysis()
     except Exception:

@@ -1,19 +1,11 @@
-"""Pallas-TPU compatibility: compiler-params class rename.
-
-JAX ≥ 0.6 spells the Mosaic compiler options ``pltpu.CompilerParams``;
-0.4.x–0.5.x spell it ``pltpu.TPUCompilerParams``. Same constructor surface
-for the options this repo uses (``dimension_semantics``).
-"""
+"""Pallas-TPU Mosaic compiler parameters (``pltpu.CompilerParams``)."""
 from __future__ import annotations
 
 from jax.experimental.pallas import tpu as pltpu
 
-_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
-
 
 def tpu_compiler_params(*, dimension_semantics: tuple | None = None, **kw):
-    """Build the installed JAX's Mosaic compiler-params object."""
+    """Build the Mosaic compiler-params object."""
     if dimension_semantics is not None:
         kw["dimension_semantics"] = dimension_semantics
-    return _PARAMS_CLS(**kw)
+    return pltpu.CompilerParams(**kw)

@@ -1,74 +1,30 @@
-"""Mesh / sharding compatibility.
-
-Three drifts are adapted:
-
-* ``jax.make_mesh`` grew an ``axis_types=`` kwarg (with
-  ``jax.sharding.AxisType``) in 0.5.x; 0.4.x takes only (shapes, names).
-* ``jax.set_mesh`` (0.6) / ``jax.sharding.use_mesh`` (0.5) install the
-  *abstract* mesh that ``with_sharding_constraint(PartitionSpec)`` reads at
-  trace time; on 0.4.x the equivalent is the classic ``with mesh:``
-  thread-resource context.
-* the active-mesh query is ``jax.sharding.get_abstract_mesh()`` on new JAX;
-  on 0.4.x it is the physical mesh of the thread-resource env.
-* ``shard_map`` was promoted to ``jax.shard_map`` in 0.5.x; on 0.4.x it
-  lives in ``jax.experimental.shard_map`` (found by the lint pass: the
-  ``jax.shard_map`` spelling made the sequence-sharded KV-cache path an
-  AttributeError on 0.4.x the moment a mesh was actually in scope).
-"""
+"""Mesh / sharding API: mesh construction, the ambient (abstract) mesh
+that ``with_sharding_constraint(PartitionSpec)`` reads at trace time,
+and ``shard_map``."""
 from __future__ import annotations
 
 import contextlib
 
 import jax
 
-_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
 
 def make_mesh(shape: tuple, axes: tuple):
-    """``jax.make_mesh`` with Auto axis types where the API has them."""
-    if _AXIS_TYPE is not None:
-        try:
-            return jax.make_mesh(
-                shape, axes, axis_types=(_AXIS_TYPE.Auto,) * len(axes))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 @contextlib.contextmanager
 def set_mesh(mesh):
-    """Install `mesh` as the ambient (abstract) mesh for tracing.
-
-    Prefers ``jax.set_mesh`` / ``jax.sharding.use_mesh``; on 0.4.x falls
-    back to the ``with mesh:`` resource env, which is what
-    ``with_sharding_constraint`` consults there.
-    """
-    setter = getattr(jax, "set_mesh", None) \
-        or getattr(jax.sharding, "use_mesh", None)
-    cm = setter(mesh) if setter is not None else mesh
-    with cm:
+    """Install `mesh` as the ambient (abstract) mesh for tracing."""
+    with jax.set_mesh(mesh):
         yield mesh
 
 
 def active_mesh():
-    """The mesh in scope at trace time (abstract on new JAX, the resource
-    env's physical mesh on 0.4.x); None when unmeshed."""
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        try:
-            m = get_abstract()
-        except Exception:
-            m = None
-        if m is not None and m.axis_names:
-            return m
-    try:        # 0.4.x: `with mesh:` populates the thread-resource env
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+    """The abstract mesh in scope at trace time; None when unmeshed."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def active_mesh_axis_names() -> tuple:
@@ -78,16 +34,11 @@ def active_mesh_axis_names() -> tuple:
 
 
 def mesh_axis_sizes(mesh) -> dict:
-    """{axis name: size} for physical or abstract meshes on any version."""
-    sizes = getattr(mesh, "axis_sizes", None)
-    if sizes is not None:
-        return dict(zip(mesh.axis_names, sizes))
-    return dict(zip(mesh.axis_names, mesh.devices.shape))
+    """{axis name: size} for physical or abstract meshes."""
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` (0.5+) / ``jax.experimental.shard_map`` (0.4.x)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """``jax.shard_map`` with keyword mesh/specs."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)

@@ -1,10 +1,6 @@
-"""Pytree compatibility: ``jax.tree.map`` appeared in 0.4.25; older JAX
-only has ``jax.tree_util.tree_map`` (same semantics incl. ``is_leaf``)."""
+"""Pytree map (``jax.tree.map``, including ``is_leaf``)."""
 from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "tree") and hasattr(jax.tree, "map"):
-    tree_map = jax.tree.map
-else:                                         # pragma: no cover — old JAX
-    tree_map = jax.tree_util.tree_map
+tree_map = jax.tree.map

@@ -13,6 +13,7 @@ rides along so the MXU sees a (g x block_s) matmul instead of a GEMV.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -59,23 +60,28 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
 
 def pick_block_s(s: int, d: int, itemsize: int,
                  target_bytes: int = 1 << 16) -> int:
-    """KV block length: a whole number of DRAM rows, >= 8 sublanes, and a
-    divisor of the (padded) sequence."""
-    rows_per_token = d * itemsize            # bytes per token per head
-    bs = max(8, target_bytes // rows_per_token)
-    while (bs * rows_per_token) % DRAM_ROW_BYTES and bs > 8:
-        bs -= 8
-    while s % bs and bs > 8:
-        bs -= 8
-    return max(8, bs)
+    """KV block length: a whole number of DRAM rows, a multiple of 8
+    sublanes, and a divisor of the (padded) sequence. Falls back to the
+    largest multiple of 8 dividing ``s`` when no row-aligned block does."""
+    token_bytes = d * itemsize               # bytes per token per head
+    quantum = max(8, DRAM_ROW_BYTES // math.gcd(DRAM_ROW_BYTES, token_bytes))
+    cap = max(quantum, target_bytes // token_bytes)
+    for step in (quantum, 8):                # whole DRAM rows, then sublanes
+        bs = cap - cap % step
+        while bs > step and s % bs:
+            bs -= step
+        if s % bs == 0:
+            return bs
+    return 8
 
 
 @functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
 def flash_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                 pos: jax.Array, block_s: int | None = None,
-                 interpret: bool = True) -> jax.Array:
+                 pos: jax.Array, block_s: int | None = None, *,
+                 interpret: bool) -> jax.Array:
     """q: (b, h, d); caches: (b, h_kv, s, d); pos: scalar int32 (slots >
-    pos are unwritten). Returns (b, h, d)."""
+    pos are unwritten). Returns (b, h, d). ``interpret=True`` runs the
+    Pallas interpreter (CPU tests); ``False`` compiles with Mosaic."""
     b, h, d = q.shape
     _, hkv, s, _ = k_cache.shape
     g = h // hkv

@@ -8,10 +8,11 @@ from .ref import flash_decode_ref
 
 
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
-                     pos, use_kernel: bool = True,
-                     interpret: bool = True) -> jax.Array:
-    """Row-granularity GQA decode attention; falls back to the jnp oracle
-    with `use_kernel=False`."""
+                     pos, use_kernel: bool = True, *,
+                     interpret: bool) -> jax.Array:
+    """Row-granularity GQA decode attention; `use_kernel=False` runs the
+    jnp oracle instead. `interpret` selects the Pallas interpreter (CPU)
+    or the Mosaic-compiled kernel (TPU); callers always name it."""
     if not use_kernel:
         return flash_decode_ref(q, k_cache, v_cache, pos)
     return flash_decode(q, k_cache, v_cache, pos, interpret=interpret)

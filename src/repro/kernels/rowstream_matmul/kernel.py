@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from ...compat.pallas import tpu_compiler_params
+
 DRAM_ROW_BYTES = 4096
 MXU = 128
 
@@ -46,10 +48,11 @@ def pick_bk(k: int, n: int, itemsize: int, vmem_budget: int = 1 << 21) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
-def rowstream_matmul(x: jax.Array, w: jax.Array, bk: int | None = None,
-                     interpret: bool = True) -> jax.Array:
+def rowstream_matmul(x: jax.Array, w: jax.Array, bk: int | None = None, *,
+                     interpret: bool) -> jax.Array:
     """x: (m, k) @ w: (k, n) -> (m, n). Weight streamed in row-aligned
-    K-blocks of the full N width."""
+    K-blocks of the full N width. ``interpret=True`` runs the Pallas
+    interpreter (CPU tests); ``False`` compiles with Mosaic."""
     m, k = x.shape
     k2, n = w.shape
     assert k == k2, (x.shape, w.shape)
@@ -66,6 +69,9 @@ def rowstream_matmul(x: jax.Array, w: jax.Array, bk: int | None = None,
         ],
         out_specs=pl.BlockSpec((m, n), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        # The K axis is a reduction into the resident output block.
+        compiler_params=tpu_compiler_params(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x, w)
     return out.astype(x.dtype)

@@ -8,10 +8,11 @@ from .ref import rwkv_scan_ref
 
 
 def time_mix(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
-             u: jax.Array, use_kernel: bool = True,
-             interpret: bool = True):
-    """Chunk-parallel RWKV6 recurrence; `use_kernel=False` falls back to
-    the sequential jnp oracle."""
+             u: jax.Array, use_kernel: bool = True, *,
+             interpret: bool):
+    """Chunk-parallel RWKV6 recurrence; `use_kernel=False` runs the
+    sequential jnp oracle instead. `interpret` selects the Pallas
+    interpreter (CPU) or the Mosaic-compiled kernel (TPU)."""
     if not use_kernel:
         return rwkv_scan_ref(r, k, v, w, u)
     return rwkv_scan(r, k, v, w, u, interpret=interpret)

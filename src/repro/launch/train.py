@@ -28,6 +28,7 @@ from ..data.pipeline import make_pipeline
 from ..distributed import checkpoint as ckpt
 from ..models.registry import get_adapter
 from ..train.train_step import TrainState, make_train_step, train_state_init
+from .compile_cache import setup_compile_cache
 from .mesh import make_mesh
 from ..compat import set_mesh, tree_map
 
@@ -66,6 +67,7 @@ def main(argv=None) -> int:
     ap.add_argument("--async-ckpt", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     mesh_shape = tuple(int(x) for x in args.mesh.split("x"))
     cfg, adapter, mesh, step, tp = build(

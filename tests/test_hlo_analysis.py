@@ -86,11 +86,10 @@ def test_cost_analysis_counts_while_once():
 
 
 def test_normalize_cost_analysis_shapes():
-    """Both historical return shapes of Compiled.cost_analysis() normalize
-    to the same flat dict."""
+    """Compiled.cost_analysis() returns a flat dict (or nothing); both
+    normalize to a flat dict."""
     assert normalize_cost_analysis({"flops": 2.0}) == {"flops": 2.0}
-    assert normalize_cost_analysis([{"flops": 2.0}]) == {"flops": 2.0}
-    assert normalize_cost_analysis([]) == {}
+    assert normalize_cost_analysis({}) == {}
     assert normalize_cost_analysis(None) == {}
 
 

@@ -23,7 +23,7 @@ def test_rowstream_matmul(m, k, n, dtype):
     k1, k2 = jax.random.split(KEY)
     x = jax.random.normal(k1, (m, k), dtype)
     w = jax.random.normal(k2, (k, n), dtype)
-    out = rowstream_matmul(x, w)
+    out = rowstream_matmul(x, w, interpret=True)
     ref = rowstream_matmul_ref(x, w)
     tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -51,7 +51,7 @@ def test_flash_decode(b, h, hkv, s, d, dtype):
     kc = jax.random.normal(ks[1], (b, hkv, s, d), dtype)
     vc = jax.random.normal(ks[2], (b, hkv, s, d), dtype)
     pos = jnp.array(s // 2, jnp.int32)
-    out = flash_decode(q, kc, vc, pos)
+    out = flash_decode(q, kc, vc, pos, interpret=True)
     ref = flash_decode_ref(q, kc, vc, pos)
     tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -67,10 +67,10 @@ def test_flash_decode_masks_future():
     kc = jax.random.normal(ks[1], (b, hkv, s, d))
     vc = jax.random.normal(ks[2], (b, hkv, s, d))
     pos = jnp.array(10, jnp.int32)
-    out1 = flash_decode(q, kc, vc, pos)
+    out1 = flash_decode(q, kc, vc, pos, interpret=True)
     kc2 = kc.at[:, :, 11:].set(1e9)
     vc2 = vc.at[:, :, 11:].set(-1e9)
-    out2 = flash_decode(q, kc2, vc2, pos)
+    out2 = flash_decode(q, kc2, vc2, pos, interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2),
                                rtol=1e-6)
 
@@ -94,7 +94,7 @@ def test_rwkv_scan(b, s, H, hd, chunk):
     v = jax.random.normal(ks[2], (b, s, H, hd))
     w = jax.nn.sigmoid(jax.random.normal(ks[3], (b, s, H, hd))) * 0.5 + 0.4
     u = jax.random.normal(ks[4], (H, hd)) * 0.1
-    o, S = rwkv_scan(r, k, v, w, u, chunk=chunk)
+    o, S = rwkv_scan(r, k, v, w, u, chunk=chunk, interpret=True)
     o_ref, S_ref = rwkv_scan_ref(r, k, v, w, u)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
                                rtol=1e-3, atol=1e-3)
@@ -113,7 +113,7 @@ def test_rwkv_scan_extreme_decay_stable():
     w = jnp.where(jax.random.bernoulli(ks[3], 0.4, (b, s, H, hd)),
                   1e-35, 0.9)
     u = jnp.zeros((H, hd))
-    o, S = rwkv_scan(r, k, v, w, u, chunk=8)
+    o, S = rwkv_scan(r, k, v, w, u, chunk=8, interpret=True)
     o_ref, S_ref = rwkv_scan_ref(r, k, v, w, u)
     assert bool(jnp.isfinite(o).all()) and bool(jnp.isfinite(S).all())
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),

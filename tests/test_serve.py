@@ -201,3 +201,44 @@ def test_admission_check_blocks():
     # FIFO order preserved: r0 admitted; r1 blocks the queue head
     assert [r.rid for _, r in adm] == [0]
     assert b.queue[0].rid == 1
+
+
+# --- serving entry point (repro.launch.serve) ---------------------------
+
+def test_serve_run_completes_every_request():
+    from repro.launch import serve
+    args = serve.parse_args(["--arch", "h2o-danube-1.8b", "--reduced",
+                             "--requests", "3", "--slots", "2",
+                             "--max-new", "4"])
+    run = serve.run(args)
+    assert run.completed == 3
+    assert run.tokens_out == 3 * 4
+    assert run.first_tokens.shape == (2, 1)
+    assert run.first_logits.shape[:2] == (2, 1)
+    assert np.isfinite(run.first_logits.astype(np.float32)).all()
+    assert run.tokens_per_s > 0 and run.compile_s > 0
+
+
+def test_compile_cache_left_to_env(monkeypatch, tmp_path):
+    import jax
+    from repro.launch.compile_cache import setup_compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert setup_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_defaults_to_repo_dir(monkeypatch):
+    import os
+    import jax
+    from repro.launch.compile_cache import REPO_CACHE_DIR, setup_compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = setup_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    repo = os.path.join(os.path.dirname(__file__), "..")
+    assert os.path.samefile(REPO_CACHE_DIR.parent, repo)
+    assert path == str(REPO_CACHE_DIR) and REPO_CACHE_DIR.name == ".jax_cache"

@@ -28,6 +28,7 @@ class _FakeMesh:
         import numpy as np
         self.devices = np.zeros(shape)
         self.axis_names = names
+        self.axis_sizes = tuple(shape)
 
 
 def test_concretize_divisibility():
