@@ -50,9 +50,7 @@ def run(workers: int | None = None) -> dict:
         t0 = time.time()
         sim = SystemSim(cfg, n_channels=nch)
         stream = bulk_stream(nch * BULK_BYTES_PER_CHANNEL)
-        t_sim = time.time()
         res = sim.run(stream, workers=workers)
-        sim_secs = time.time() - t_sim
         bulk[name] = {
             "n_channels": nch,
             "makespan_ns": round(res.total_ns, 1),
@@ -60,9 +58,6 @@ def run(workers: int | None = None) -> dict:
             "peak_cube_gbps": round(cfg.cube_bw_gbps, 1),
             "lbr": round(res.load_balance_ratio, 4),
             "wall_s": round(time.time() - t0, 2),
-            # Engine time alone (stream build / setup excluded): the
-            # wall-time tracker this benchmark exists to record.
-            "sim_seconds": round(sim_secs, 3),
         }
 
     # Paper headline: +12.5 % aggregate bandwidth from the 4 extra
@@ -79,10 +74,8 @@ def run(workers: int | None = None) -> dict:
                                          scale=DECODE_SCALE,
                                          n_ops=DECODE_OPS)
         t0 = time.time()
-        t_sim = time.time()
         res = SystemSim(acc.mem_cfg, n_channels=acc.n_channels).run(
             stream, workers=workers)
-        sim_secs = time.time() - t_sim
         model_ns = stream_mem_ns(stream, acc)
         rel = abs(res.total_ns - model_ns) / model_ns
         decode[name] = {
@@ -94,7 +87,6 @@ def run(workers: int | None = None) -> dict:
             "rel_err": round(rel, 4),
             "lbr": round(res.load_balance_ratio, 4),
             "wall_s": round(time.time() - t0, 2),
-            "sim_seconds": round(sim_secs, 3),
         }
         # The TPOT cross-validation band holds at full cube width, and
         # the address map keeps the cube balanced.

@@ -47,8 +47,7 @@ two :class:`~repro.serve.replay.ArrivalProcess` disciplines — bursty
 replays the *unscaled* (``scale=1.0``) weight slice end to end through
 the hybrid SystemSim: GB-scale decode steps priced by the calibrated
 queue-window model (``hybrid_fraction`` reported), the CI-feasibility
-proof for production-size traces. Every cell carries its wall-clock
-``sim_seconds`` so the regression gate tracks the speedup trajectory.
+proof for production-size traces.
 
 The ``prefill`` section turns prompt ingestion on
 (``prefill_chunk_tokens``): prompts stream through the memory system in
@@ -102,9 +101,7 @@ def _cell(policy: str, rate_rps: float, n_requests: int, *,
         length_scale=LENGTH_SCALE, scale=scale, n_slots=N_SLOTS,
         n_channels=n_channels, keep_traces=keep_traces,
         sim_mode=sim_mode, **arrival_kw)
-    t0 = time.perf_counter()
-    res = eng.run()
-    return res, acc, round(time.perf_counter() - t0, 3)
+    return eng.run(), acc
 
 
 def _check_conservation(res) -> int:
@@ -138,9 +135,9 @@ def _obs_section(scale: float, n_requests: int) -> dict:
     out: dict = {}
     for policy in POLICIES:
         kw = dict(scale=scale, kind="bursty", burst_size=4)
-        bare, _, _ = _cell(policy, 2e5, n_requests, **kw)
+        bare, _ = _cell(policy, 2e5, n_requests, **kw)
         col = ObsCollector(probe=MetricsProbe(window_ns=200.0))
-        obs, _, _ = _cell(policy, 2e5, n_requests, collector=col, **kw)
+        obs, _ = _cell(policy, 2e5, n_requests, collector=col, **kw)
         assert bare.summary() == obs.summary(), policy
         assert ([s.dur_ns for s in bare.steps]
                 == [s.dur_ns for s in obs.steps]), policy
@@ -159,7 +156,6 @@ def _obs_section(scale: float, n_requests: int) -> dict:
 
 
 def run(reduced: bool = False) -> dict:
-    t_run0 = time.perf_counter()
     scale = 2 ** -13 if reduced else 2 ** -12
     n_req = {"near": 2, "sweep": 5} if reduced else {"near": 4, "sweep": 10}
 
@@ -173,8 +169,8 @@ def run(reduced: bool = False) -> dict:
     xval = {}
     near = {}
     for policy in POLICIES:
-        res, acc, secs = _cell(policy, NEAR_ZERO_RPS, n_req["near"],
-                               scale=scale, keep_traces=True)
+        res, acc = _cell(policy, NEAR_ZERO_RPS, n_req["near"],
+                         scale=scale, keep_traces=True)
         assert res.completed == n_req["near"], (policy, res.completed)
         assert max(s.n_active for s in res.steps) == 1, policy
         meas = float(np.mean([s.dur_ns for s in res.steps]))
@@ -185,8 +181,7 @@ def run(reduced: bool = False) -> dict:
         xval[policy] = {"mean_step_ns": round(meas, 1),
                         "analytic_step_ns": round(model, 1),
                         "rel_err": round(rel, 4),
-                        "kv_bytes": kv_bytes,
-                        "sim_seconds": secs}
+                        "kv_bytes": kv_bytes}
         if not reduced:
             # The established engine_xval band, now reached from a full
             # serving loop instead of a hand-built decode slice.
@@ -211,11 +206,10 @@ def run(reduced: bool = False) -> dict:
             offered_rps=NEAR_ZERO_RPS, **res0.summary())
         for rho in RHOS:
             rate = rho * cap_rps
-            res, _, secs = _cell(policy, rate, n_req["sweep"], scale=scale)
+            res, _ = _cell(policy, rate, n_req["sweep"], scale=scale)
             assert res.completed == n_req["sweep"], (policy, rho)
             cells[f"{policy}/rho{rho}"] = dict(
-                offered_rps=round(rate, 1), sim_seconds=secs,
-                **res.summary())
+                offered_rps=round(rate, 1), **res.summary())
     out["cells"] = cells
 
     # --- bursty / closed-loop arrival disciplines --------------------------
@@ -225,23 +219,23 @@ def run(reduced: bool = False) -> dict:
     kinds = {}
     for policy in POLICIES:
         rate = RHOS[0] * cap_rps
-        res, _, secs = _cell(policy, rate, n_req["sweep"], scale=scale,
-                             kind="bursty", burst_size=4)
+        res, _ = _cell(policy, rate, n_req["sweep"], scale=scale,
+                       kind="bursty", burst_size=4)
         assert res.completed == n_req["sweep"], (policy, "bursty")
         # A whole burst lands in one admission window: the batch fills
         # deeper than the near-zero (serial) regime ever does.
         assert max(s.n_active for s in res.steps) > 1, (policy, "bursty")
         kinds[f"{policy}/bursty"] = dict(
-            offered_rps=round(rate, 1), sim_seconds=secs, **res.summary())
-        res, _, secs = _cell(policy, rate, n_req["sweep"], scale=scale,
-                             kind="closed", n_users=N_SLOTS,
-                             think_ns=1e9 / rate)
+            offered_rps=round(rate, 1), **res.summary())
+        res, _ = _cell(policy, rate, n_req["sweep"], scale=scale,
+                       kind="closed", n_users=N_SLOTS,
+                       think_ns=1e9 / rate)
         assert res.completed == n_req["sweep"], (policy, "closed")
         # Closed loop seeds n_users at t=0: the batch starts full.
         assert res.steps[0].n_active == min(N_SLOTS, n_req["sweep"]), \
             (policy, "closed")
         kinds[f"{policy}/closed"] = dict(
-            offered_rps=round(rate, 1), sim_seconds=secs, **res.summary())
+            offered_rps=round(rate, 1), **res.summary())
     out["arrival_kinds"] = kinds
 
     # --- observability: attach-and-compare (repro.obs) ---------------------
@@ -252,15 +246,15 @@ def run(reduced: bool = False) -> dict:
     # slice — ~1e9 decomposed transactions per step, unrunnable by the
     # cycle engine. The hybrid SystemSim prices every step with the
     # calibrated queue-window model; completing here (in seconds) IS the
-    # CI-feasibility result, and sim_seconds tracks it in the baseline.
+    # CI-feasibility result.
     unscaled = {}
     for policy in POLICIES:
-        res, _, secs = _cell(policy, NEAR_ZERO_RPS, n_req["near"],
-                             scale=1.0, sim_mode="hybrid")
+        res, _ = _cell(policy, NEAR_ZERO_RPS, n_req["near"],
+                       scale=1.0, sim_mode="hybrid")
         assert res.completed == n_req["near"], (policy, "unscaled")
         s = res.summary()
         assert s["hybrid_fraction"] == 1.0, (policy, s["hybrid_fraction"])
-        unscaled[policy] = dict(sim_seconds=secs, **s)
+        unscaled[policy] = s
     out["unscaled"] = unscaled
 
     # --- chunked prefill + packing-prefetch (warm sessions) ----------------
@@ -279,18 +273,18 @@ def run(reduced: bool = False) -> dict:
     n_pf = 24 if reduced else 32
     prefill = {}
     for policy in POLICIES:
-        res0, _, _ = _cell(policy, NEAR_ZERO_RPS, n_req["near"],
-                           scale=scale, sim_mode="hybrid", warm=True,
-                           prefill_chunk_tokens=chunks[0])
+        res0, _ = _cell(policy, NEAR_ZERO_RPS, n_req["near"],
+                        scale=scale, sim_mode="hybrid", warm=True,
+                        prefill_chunk_tokens=chunks[0])
         tpot0p = (float(np.mean(res0.tpots_ns)) if res0.tpots_ns
                   else float(np.mean([s.dur_ns for s in res0.steps])))
         rate = 1.5 * N_SLOTS / (tpot0p * 1e-9 * mean_out)
         for chunk in chunks:
             for overlap in (False, True):
-                res, _, secs = _cell(policy, rate, n_pf, scale=scale,
-                                     sim_mode="hybrid", warm=True,
-                                     prefill_chunk_tokens=chunk,
-                                     prefill_overlap=overlap)
+                res, _ = _cell(policy, rate, n_pf, scale=scale,
+                               sim_mode="hybrid", warm=True,
+                               prefill_chunk_tokens=chunk,
+                               prefill_overlap=overlap)
                 assert res.completed == n_pf, (policy, chunk, overlap)
                 # Every request clears prefill before its first token.
                 assert all(r.prefill_done_ns >= 0 for r in res.requests)
@@ -301,8 +295,7 @@ def run(reduced: bool = False) -> dict:
                     (policy, chunk, overlap)
                 key = (f"{policy}/chunk{chunk}/"
                        f"{'overlap' if overlap else 'stall'}")
-                prefill[key] = dict(offered_rps=round(rate, 1),
-                                    sim_seconds=secs, **s)
+                prefill[key] = dict(offered_rps=round(rate, 1), **s)
         # Packing-prefetch gate: at rho >= 1.5, overlapping prefill chunk
         # fetch with decode compute beats stalling decode on the TTFT
         # tail — dedicated prefill-only steps serialize the queue.
@@ -340,21 +333,19 @@ def run(reduced: bool = False) -> dict:
 
     # --- equal-pin headline (HBM4 x 8ch vs RoMe x 9ch) ---------------------
     if reduced:
-        out["sim_seconds"] = round(time.perf_counter() - t_run0, 3)
         return out
     pin = {}
     for policy, nch in EQUAL_PIN_CHANNELS.items():
-        res0, _, _ = _cell(policy, NEAR_ZERO_RPS, n_req["near"],
-                           scale=scale, n_channels=nch)
+        res0, _ = _cell(policy, NEAR_ZERO_RPS, n_req["near"],
+                        scale=scale, n_channels=nch)
         tpot_nz = (float(np.mean(res0.tpots_ns)) if res0.tpots_ns
                    else float(np.mean([s.dur_ns for s in res0.steps])))
         rate = RHOS[1] * N_SLOTS / (tpot_nz * 1e-9 * mean_out)
-        res, _, secs = _cell(policy, rate, n_req["sweep"], scale=scale,
-                             n_channels=nch)
+        res, _ = _cell(policy, rate, n_req["sweep"], scale=scale,
+                       n_channels=nch)
         assert res.completed == n_req["sweep"], (policy, nch)
         pin[policy] = dict(n_channels=nch, offered_rps=round(rate, 1),
-                           tpot_nz_ns=round(tpot_nz, 1), sim_seconds=secs,
-                           **res.summary())
+                           tpot_nz_ns=round(tpot_nz, 1), **res.summary())
         cells[f"{policy}/equal_pin_rho{RHOS[1]}"] = pin[policy]
     delta = (pin["hbm4_frfcfs"]["tpot_p99_ns"]
              / pin["rome_qd2"]["tpot_p99_ns"] - 1)
@@ -376,20 +367,20 @@ def run(reduced: bool = False) -> dict:
     # packing-prefetch on, warm sessions.
     pinp = {}
     for policy, nch in EQUAL_PIN_CHANNELS.items():
-        res0, _, _ = _cell(policy, NEAR_ZERO_RPS, n_req["near"],
-                           scale=scale, n_channels=nch, sim_mode="hybrid",
-                           warm=True, prefill_chunk_tokens=chunks[0])
+        res0, _ = _cell(policy, NEAR_ZERO_RPS, n_req["near"],
+                        scale=scale, n_channels=nch, sim_mode="hybrid",
+                        warm=True, prefill_chunk_tokens=chunks[0])
         tpot0p = (float(np.mean(res0.tpots_ns)) if res0.tpots_ns
                   else float(np.mean([s.dur_ns for s in res0.steps])))
         rate = 1.5 * N_SLOTS / (tpot0p * 1e-9 * mean_out)
-        res, _, secs = _cell(policy, rate, n_pf, scale=scale,
-                             n_channels=nch, sim_mode="hybrid", warm=True,
-                             prefill_chunk_tokens=chunks[0],
-                             prefill_overlap=True,
-                             kind="bursty", burst_size=4)
+        res, _ = _cell(policy, rate, n_pf, scale=scale,
+                       n_channels=nch, sim_mode="hybrid", warm=True,
+                       prefill_chunk_tokens=chunks[0],
+                       prefill_overlap=True,
+                       kind="bursty", burst_size=4)
         assert res.completed == n_pf, (policy, nch, "prefill_pin")
         pinp[policy] = dict(n_channels=nch, offered_rps=round(rate, 1),
-                            sim_seconds=secs, **res.summary())
+                            **res.summary())
         prefill[f"{policy}/equal_pin"] = pinp[policy]
     pdelta = (pinp["rome_qd2"]["goodput_rps"]
               / pinp["hbm4_frfcfs"]["goodput_rps"] - 1)
@@ -404,7 +395,6 @@ def run(reduced: bool = False) -> dict:
     # baseline records, not an assumption the gate bakes in.
     assert abs(pdelta) < 0.5, out["prefill_headline"]
 
-    out["sim_seconds"] = round(time.perf_counter() - t_run0, 3)
     return out
 
 

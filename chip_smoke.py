@@ -248,7 +248,7 @@ def phase_simulator() -> dict:
                        / "serve_trace.json").read_text())["metrics"]
     prefix = f"cells.{SIM_POLICY}/equal_pin_rho{RHOS[1]}."
     expect = {k[len(prefix):]: v for k, v in base.items()
-              if k.startswith(prefix) and not k.endswith("sim_seconds")}
+              if k.startswith(prefix)}
     check(bool(expect), f"no {prefix}* cell in the baseline")
     diff = {k: (summary.get(k), v) for k, v in expect.items()
             if summary.get(k) is None or float(summary[k]) != float(v)}

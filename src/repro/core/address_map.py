@@ -13,6 +13,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from ..obs import host
 from .timing import MemSystemConfig
 
 
@@ -113,6 +114,7 @@ def _windowed_add(acc: np.ndarray, seg: np.ndarray | None, ch0: np.ndarray,
     acc += np.cumsum(d.reshape(n_segs, nch + 1), axis=1)[:, :nch]
 
 
+@host.spanned("census")
 def extent_census(amap: AddressMap, starts: np.ndarray, sizes: np.ndarray,
                   seg: np.ndarray | None = None, n_segs: int = 1
                   ) -> dict[str, np.ndarray]:

@@ -21,6 +21,8 @@ from typing import NamedTuple
 
 import numpy as np
 
+from ...obs import host
+
 
 class CmdRecord(NamedTuple):
     """One emitted memory command, for the trace sanitizer.
@@ -296,7 +298,8 @@ class ChannelRunState:
         next_sample_t = self.next_sample_t
         sample_w = core.sample_window_ns
 
-        for _ in range(max_iters):
+        iters = 0
+        for iters in range(max_iters):
             if not pending:
                 break
             # Telemetry sampling: one state snapshot per window-boundary
@@ -355,6 +358,9 @@ class ChannelRunState:
                 if refresh:
                     cand = min(cand, next_ref_t)
                 now = max(now + 1e-9, cand)
+        else:
+            iters = max_iters
+        host.count("cycle.iters", iters)
 
         self.next_ref_t = next_ref_t
         self.next_ref_unit = next_ref_unit

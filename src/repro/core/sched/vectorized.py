@@ -32,10 +32,12 @@ from __future__ import annotations
 
 import numpy as np
 
+from ...obs import host
 from .channels import make_channel_sim
 from .core import SimResult, Txn
 
 
+@host.spanned("cycle.advance")
 def advance_states(states, batch: int = 2048) -> None:
     """Drain a set of live :class:`~.core.ChannelRunState`\\ s in lockstep
     ``batch``-iteration slices (the same sweep loop as
@@ -62,8 +64,10 @@ def run_channels(kind: str, kwargs: dict, txns_per_channel: list[list[Txn]],
     ``[make_channel_sim(kind, **kwargs).run(t) for t in txns_per_channel]``.
     """
     n = len(txns_per_channel)
-    states = [make_channel_sim(kind, **kwargs).start_run(txns)
-              for txns in txns_per_channel]
+    host.count("cycle.txns", sum(map(len, txns_per_channel)))
+    with host.span("cycle.setup"):
+        states = [make_channel_sim(kind, **kwargs).start_run(txns)
+                  for txns in txns_per_channel]
     advance_states(states, batch)
     return [states[i].result() for i in range(n)]
 

@@ -41,6 +41,7 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from ..obs import host
 from ..workloads.stream import ExtentRecord, ExtentStream
 from .address_map import AddressMap, make_address_map
 from .pool import get_pool
@@ -240,6 +241,7 @@ class SystemSim:
         g = self.amap.stripe_bytes
         return range(addr // g, (addr + nbytes - 1) // g + 1)
 
+    @host.spanned("census")
     def decompose(self, stream: ExtentStream) -> dict[int, list[Txn]]:
         """Per-channel transaction streams for a timed extent stream.
 
@@ -457,6 +459,7 @@ class SystemSim:
 
     # -- run ---------------------------------------------------------------
 
+    @host.spanned("pricing")
     def run(self, stream: ExtentStream, workers: int = 1,
             start_ns: float | None = None) -> SystemResult:
         """Simulate or price a timed extent stream on all loaded
@@ -490,6 +493,8 @@ class SystemSim:
                                       workers, pressure=pressure)
         else:
             res = self._run_cycle(self._rebase(stream, start_ns), workers)
+        host.count("steps.analytic" if res.mode == "analytic"
+                   else "steps.cycle")
         if self.probe is not None:
             # Cycle-path telemetry clocks are relative to the rebased
             # stream; t0 places the windows back on the caller's clock.
@@ -503,6 +508,7 @@ class SystemSim:
             return stream
         return stream.shifted(-start_ns)
 
+    @host.spanned("cycle.run")
     def _run_cycle(self, stream: ExtentStream, workers: int = 1,
                    pressure: float = 0.0) -> SystemResult:
         per_channel = self.decompose(stream)
@@ -548,6 +554,7 @@ class SystemSim:
         See :meth:`run_steps` for the warm-vs-reset contract."""
         return WarmRunState(self)
 
+    @host.spanned("pricing")
     def run_steps(self, streams: "list[ExtentStream]",
                   workers: int = 1,
                   starts_ns: "list[float] | None" = None,
@@ -631,6 +638,8 @@ class SystemSim:
                     cycle_steps.append((i, pressure))
         else:
             cycle_steps = [(i, 0.0) for i in range(len(streams))]
+        host.count("steps.analytic", len(streams) - len(cycle_steps))
+        host.count("steps.cycle", len(cycle_steps))
 
         def _cycle_stream(i: int) -> ExtentStream:
             s = streams[i]
@@ -754,6 +763,7 @@ class WarmRunState:
         analytically priced step (0.0 in pure cycle mode)."""
         return self._carry
 
+    @host.spanned("pricing")
     def step(self, stream: ExtentStream,
              start_ns: float | None = None) -> SystemResult:
         """Price/simulate one step on the session clock. ``start_ns``
@@ -781,6 +791,8 @@ class WarmRunState:
                 res = self._cycle_step(stream, start, pressure_eff)
         else:
             res = self._cycle_step(stream, start, 0.0)
+        host.count("steps.analytic" if res.mode == "analytic"
+                   else "steps.cycle")
         if sys_.probe is not None:
             # Warm sessions run on the absolute clock already (t0=0);
             # analytic steps still need their start for placement.
@@ -814,20 +826,23 @@ class WarmRunState:
             queue_pressure=pressure_eff,
         )
 
+    @host.spanned("cycle.run")
     def _cycle_step(self, stream: ExtentStream, start: float,
                     pressure: float) -> SystemResult:
         sys_ = self.system
         items = sorted(sys_.decompose(stream).items())
+        host.count("cycle.txns", sum(len(txns) for _, txns in items))
         stepped = []
-        for c, txns in items:
-            st = self._states.get(c)
-            if st is None:
-                st = make_channel_sim(
-                    self._kind, **self._kwargs).start_run(txns)
-                self._states[c] = st
-            else:
-                st.feed(txns)
-            stepped.append((c, st))
+        with host.span("cycle.setup"):
+            for c, txns in items:
+                st = self._states.get(c)
+                if st is None:
+                    st = make_channel_sim(
+                        self._kind, **self._kwargs).start_run(txns)
+                    self._states[c] = st
+                else:
+                    st.feed(txns)
+                stepped.append((c, st))
         advance_states([st for _, st in stepped])
         nch = sys_.amap.n_channels
         ch_bytes = np.zeros(nch, dtype=np.int64)

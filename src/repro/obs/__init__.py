@@ -23,11 +23,16 @@ The observability subsystem turns end-of-run scalars into timelines
 ``demo``
     The one-command equal-pin HBM4-vs-RoMe trace pair
     (examples/obs_trace.py).
+``host``
+    Host-clock spans and counters: where the simulator spends the
+    host's wall time (off by default). Every other span here is on the
+    simulated clock.
 
 Attach points: ``SystemSim.attach_probe(probe)`` for raw extent runs,
 ``build_replay(..., collector=ObsCollector(probe=...))`` for serve
 replays, ``ClusterSim(..., collector=...)`` for fleet runs.
 """
+from . import host
 from .export import (chrome_trace_events, counter_final, counter_series,
                      load_chrome_trace, slices, trace_row_hit_rate,
                      trace_total_bytes, write_chrome_trace,
@@ -44,5 +49,5 @@ __all__ = [
     "is_highwater",
     "chrome_trace_events", "write_chrome_trace", "write_metrics_jsonl",
     "load_chrome_trace", "slices", "counter_series", "counter_final",
-    "trace_row_hit_rate", "trace_total_bytes",
+    "trace_row_hit_rate", "trace_total_bytes", "host",
 ]

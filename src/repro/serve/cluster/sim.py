@@ -52,6 +52,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ...core.system_sim import SystemSim
+from ...obs import host
 from ..replay.arrivals import ArrivalProcess, RequestSpec
 from ..replay.recorder import (KV_BASE_ADDR, ServeTraceRecorder,
                                make_kv_cache, weight_step_stream)
@@ -298,6 +299,7 @@ class ClusterSim:
     signature cache whose stats land in the result.
     """
 
+    @host.spanned("build")
     def __init__(self, workload: str = "deepseek-v3",
                  policy: str = "hbm4_frfcfs",
                  n_replicas: int = 4,
@@ -371,6 +373,7 @@ class ClusterSim:
 
     # -- fleet loop ----------------------------------------------------------
 
+    @host.spanned("fleet.run")
     def run(self) -> ClusterResult:
         arr = self.arrivals
         reps = self.replicas

@@ -31,6 +31,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ...core.system_sim import SystemSim
+from ...obs import host
 from .recorder import ServeTraceRecorder, StepTrace
 
 
@@ -248,6 +249,7 @@ class ReplayEngine:
         return result
 
 
+@host.spanned("build")
 def build_replay(workload: str = "deepseek-v3",
                  policy: str = "hbm4_frfcfs",
                  rate_rps: float = 1e5,

@@ -41,6 +41,7 @@ from dataclasses import dataclass, replace
 
 import numpy as np
 
+from ...obs import host
 from ...trace.layergraph import LayerOp, decode_ops
 from ...workloads import (ExtentStream, from_layer_ops, layer_ops_span_ns,
                           scale_layer_ops)
@@ -200,6 +201,7 @@ class ServeTraceRecorder:
         self._worst_pages[req.rid] = worst
         return True
 
+    @host.spanned("recorder.submit")
     def submit_due(self, now_ns: float) -> list[Request]:
         """Move every arrived spec into the batcher's wait queue."""
         out = []
@@ -228,6 +230,7 @@ class ServeTraceRecorder:
 
     # -- one decode step -----------------------------------------------------
 
+    @host.spanned("recorder.step")
     def step(self, now_ns: float) -> StepTrace | None:
         """Run one scheduling iteration + step at ``now_ns``.
 
@@ -267,34 +270,37 @@ class ServeTraceRecorder:
         pack = self.batcher.prefill_pack()
         prefill_only = bool(pack) and not self.prefill_overlap
         index = self.batcher.steps
-        streams = [self.weight_stream.shifted(now_ns)] \
-            if self.weight_stream else []
-        kv_ns = now_ns + self.kv_offset_ns
-        slot_of = {}
-        decoding = []
-        for slot, req in active:
-            slot_of[req.rid] = slot
-            if prefill_only or not req.prefill_done:
-                continue
-            decoding.append(req.rid)
-            streams.append(
-                self.cache.read_stream(slot, self.kv_base_addr,
-                                       arrival_ns=kv_ns).retagged(req.rid)
-                + self.cache.append_stream(slot, self.kv_base_addr,
-                                           arrival_ns=kv_ns)
-                .retagged(req.rid))
-        for slot, req, n in pack:
-            # Chunk attention reads the context prefilled so far (empty
-            # on the first chunk), then the chunk's K/V lands as
-            # row-granular page runs.
-            streams.append(
-                (self.cache.read_stream(slot, self.kv_base_addr,
-                                        arrival_ns=kv_ns)
-                 + self.cache.append_chunk_stream(slot, n,
-                                                  self.kv_base_addr,
-                                                  arrival_ns=kv_ns))
-                .retagged(req.rid))
-        stream = ExtentStream.interleave(streams)
+        with host.span("recorder.kv_streams"):
+            streams = [self.weight_stream.shifted(now_ns)] \
+                if self.weight_stream else []
+            kv_ns = now_ns + self.kv_offset_ns
+            slot_of = {}
+            decoding = []
+            for slot, req in active:
+                slot_of[req.rid] = slot
+                if prefill_only or not req.prefill_done:
+                    continue
+                decoding.append(req.rid)
+                streams.append(
+                    self.cache.read_stream(slot, self.kv_base_addr,
+                                           arrival_ns=kv_ns).retagged(req.rid)
+                    + self.cache.append_stream(slot, self.kv_base_addr,
+                                               arrival_ns=kv_ns)
+                    .retagged(req.rid))
+            for slot, req, n in pack:
+                # Chunk attention reads the context prefilled so far (empty
+                # on the first chunk), then the chunk's K/V lands as
+                # row-granular page runs.
+                streams.append(
+                    (self.cache.read_stream(slot, self.kv_base_addr,
+                                            arrival_ns=kv_ns)
+                     + self.cache.append_chunk_stream(slot, n,
+                                                      self.kv_base_addr,
+                                                      arrival_ns=kv_ns))
+                    .retagged(req.rid))
+        with host.span("recorder.interleave"):
+            stream = ExtentStream.interleave(streams)
+        host.count("recorder.records", len(stream))
         finished = self.batcher.record_tokens(
             np.zeros(self.batcher.n_slots, np.int32),
             decode=not prefill_only)

@@ -28,6 +28,8 @@ from __future__ import annotations
 from dataclasses import dataclass, replace
 from typing import Iterable, Iterator
 
+from ..obs import host
+
 KINDS = ("read", "write")
 
 
@@ -173,19 +175,21 @@ class ExtentStream:
         cached = self._memo.get("arrays")
         if cached is None:
             import numpy as np
-            n = len(self._records)
-            addr = np.empty(n, np.int64)
-            nbytes = np.empty(n, np.int64)
-            is_write = np.empty(n, bool)
-            arrival = np.empty(n, np.float64)
-            for i, r in enumerate(self._records):
-                addr[i] = r.addr
-                nbytes[i] = r.nbytes
-                is_write[i] = r.kind == "write"
-                arrival[i] = r.arrival_ns
-            for a in (addr, nbytes, is_write, arrival):
-                a.setflags(write=False)
-            cached = self._memo["arrays"] = (addr, nbytes, is_write, arrival)
+            with host.span("census"):
+                n = len(self._records)
+                addr = np.empty(n, np.int64)
+                nbytes = np.empty(n, np.int64)
+                is_write = np.empty(n, bool)
+                arrival = np.empty(n, np.float64)
+                for i, r in enumerate(self._records):
+                    addr[i] = r.addr
+                    nbytes[i] = r.nbytes
+                    is_write[i] = r.kind == "write"
+                    arrival[i] = r.arrival_ns
+                for a in (addr, nbytes, is_write, arrival):
+                    a.setflags(write=False)
+                cached = (addr, nbytes, is_write, arrival)
+            self._memo["arrays"] = cached
         return cached
 
     # -- derivation ----------------------------------------------------------
