@@ -297,6 +297,7 @@ class ChannelRunState:
         samples = self.samples
         next_sample_t = self.next_sample_t
         sample_w = core.sample_window_ns
+        evals = pol.ready_evals
 
         iters = 0
         for iters in range(max_iters):
@@ -361,6 +362,8 @@ class ChannelRunState:
         else:
             iters = max_iters
         host.count("cycle.iters", iters)
+        if evals is not None:
+            host.count("cycle.ready_evals", pol.ready_evals - evals)
 
         self.next_ref_t = next_ref_t
         self.next_ref_unit = next_ref_unit

@@ -50,6 +50,10 @@ class SchedulerPolicy:
     #: trusting any of the readiness math below.
     trace: list | None = None
 
+    #: Readiness evaluations made by the policy's command picks, where
+    #: the policy counts them (FR-FCFS); None where it does not (RoMe).
+    ready_evals: int | None = None
+
     def begin(self, counts: dict) -> None:
         raise NotImplementedError
 
@@ -106,6 +110,7 @@ class FRFCFSOpenPagePolicy(SchedulerPolicy):
         self.ref_period = self.t.tREFIpb
         self.n_ref_units = self.n_banks
         self.bytes_per_txn = self.g.col_bytes
+        self.ready_evals = 0
 
     # -- helpers -----------------------------------------------------------
 
@@ -328,18 +333,44 @@ class FRFCFSOpenPagePolicy(SchedulerPolicy):
         candidate group; oldest (window order) on ties. Returns
         ``(txn, ready_ns)`` or ``(None, None)``."""
         for group in self._column_groups(window, now):
-            best = None
-            best_t = None
-            for tx in group:
-                b = self.banks[tx.bank]
-                if b.open_row == tx.row and b.t_act <= 1e17:
-                    r = self.col_ready(tx.bank, b, tx.is_write, tx.sid,
-                                       tx.arrival_ns)
-                    if best_t is None or r < best_t - 1e-12:
-                        best, best_t = tx, r
+            best, best_t = self._earliest_hit(group)
             if best is not None:
                 return best, best_t
         return None, None
+
+    def _earliest_hit(self, txns: list[Txn]):
+        """Earliest-ready activated row hit of `txns`, first in list order
+        on ties: ``(txn, ready_ns)`` or ``(None, None)``.
+
+        :meth:`col_ready` is evaluated once per ``(bank, is_write, sid)``
+        key, for the key's first row hit. Its result is
+        ``max(tx.arrival_ns, X)`` with ``X`` a function of the key and the
+        controller state alone, and every candidate list keeps window
+        (arrival) order within a key — the window itself, the
+        write-drain groups (``reads + aged writes`` keeps each direction
+        in order) and the same-SID list. So within a key readiness is
+        non-decreasing in list order, the first hit has the smallest
+        readiness of its key and wins its ties, and a later hit of the
+        same key can never satisfy ``r < best_t - 1e-12``: skipping it
+        leaves the pick unchanged. A non-hit marks no key.
+        ``ready_evals`` counts the evaluations."""
+        banks = self.banks
+        col_ready = self.col_ready
+        seen = set()
+        best = None
+        best_t = None
+        for tx in txns:
+            b = banks[tx.bank]
+            if b.open_row == tx.row and b.t_act <= 1e17:
+                key = (tx.bank, tx.is_write, tx.sid)
+                if key in seen:
+                    continue
+                seen.add(key)
+                r = col_ready(tx.bank, b, tx.is_write, tx.sid, tx.arrival_ns)
+                if best_t is None or r < best_t - 1e-12:
+                    best, best_t = tx, r
+        self.ready_evals += len(seen)
+        return best, best_t
 
     def _after_column(self, tx: Txn, b: _BankState, cmd_t: float) -> None:
         """Open-page: the row stays open after a column access."""
@@ -538,16 +569,9 @@ class HBM4SIDGroupPolicy(FRFCFSOpenPagePolicy):
         # burst; take a same-SID candidate if one is ready inside that
         # window.
         margin = self.t.tCCDR - self.t.tCCDS
-        same, same_t = None, None
-        for tx in window:
-            if tx.sid != cur or self._pc(tx.bank) != pc:
-                continue
-            b = self.banks[tx.bank]
-            if b.open_row == tx.row and b.t_act <= 1e17:
-                r = self.col_ready(tx.bank, b, tx.is_write, tx.sid,
-                                   tx.arrival_ns)
-                if same_t is None or r < same_t - 1e-12:
-                    same, same_t = tx, r
+        same, same_t = self._earliest_hit(
+            [tx for tx in window
+             if tx.sid == cur and self._pc(tx.bank) == pc])
         if same is not None and same_t <= best_t + margin + 1e-12:
             return same, same_t
         return best, best_t

@@ -48,6 +48,7 @@ DECLARED: dict[str, tuple[str, str]] = {
     "cycle.advance": (SPAN, "cycle engine"),
     "cycle.txns": (COUNTER, "cycle engine"),
     "cycle.iters": (COUNTER, "cycle engine"),
+    "cycle.ready_evals": (COUNTER, "cycle engine"),
     "fleet.run": (SPAN, "router / fleet"),
     "build": (SPAN, "build"),
 }

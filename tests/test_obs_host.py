@@ -3,12 +3,13 @@
 Off, the tracer is a shared null context and an empty snapshot; on, it
 accounts self and total time over nested spans, refuses undeclared
 names, never changes a replay's or a fleet's result, and every name its
-table declares is emitted by a small replay or fleet run.
+table declares is emitted by a small RoMe or HBM4 replay or fleet run.
 """
 from __future__ import annotations
 
 import pytest
 
+from repro.core import sched
 from repro.obs import host
 from repro.serve.cluster import ClusterSim
 from repro.serve.replay import build_replay
@@ -16,6 +17,7 @@ from repro.serve.replay import build_replay
 REPLAY_KW = dict(policy="rome_qd2", rate_rps=2e5, n_requests=3, seed=0,
                  scale=2 ** -14, length_scale=1 / 32, n_channels=2,
                  sim_mode="cycle", kind="bursty", burst_size=3)
+HBM4_KW = REPLAY_KW | dict(policy="hbm4_frfcfs")
 FLEET_KW = dict(policy="rome_qd2", n_replicas=2, n_requests=6,
                 rate_rps=2e5, kind="poisson", seed=0, scale=2 ** -12,
                 sim_mode="hybrid", n_channels=2, length_scale=1 / 32,
@@ -61,8 +63,9 @@ def _run(kind: str, traced: bool):
     steps = Steps()
     if traced:
         host.enable()
-    if kind == "replay":
-        eng, _ = build_replay(collector=steps, **REPLAY_KW)
+    if kind in ("replay", "hbm4"):
+        kw = REPLAY_KW if kind == "replay" else HBM4_KW
+        eng, _ = build_replay(collector=steps, **kw)
         out = eng.run()
     else:
         out = ClusterSim(collector=steps, **FLEET_KW).run()
@@ -72,7 +75,7 @@ def _run(kind: str, traced: bool):
 @pytest.fixture(scope="module")
 def runs():
     return {kind: {"bare": _run(kind, False), "traced": _run(kind, True)}
-            for kind in ("replay", "fleet")}
+            for kind in ("replay", "fleet", "hbm4")}
 
 
 def test_off_is_a_shared_null_span_and_an_empty_snapshot():
@@ -139,7 +142,7 @@ def test_undeclared_names_raise_when_on():
         host.counter(host.snapshot(), "no.such.counter")
 
 
-@pytest.mark.parametrize("kind", ["replay", "fleet"])
+@pytest.mark.parametrize("kind", ["replay", "fleet", "hbm4"])
 def test_tracing_never_changes_a_result(runs, kind):
     bare, bare_steps, off = runs[kind]["bare"]
     traced, traced_steps, on = runs[kind]["traced"]
@@ -151,7 +154,7 @@ def test_tracing_never_changes_a_result(runs, kind):
 
 def test_every_declared_name_is_emitted_and_nothing_else(runs):
     seen = set()
-    for kind in ("replay", "fleet"):
+    for kind in ("replay", "fleet", "hbm4"):
         snap = runs[kind]["traced"][2]
         seen |= set(snap["spans"]) | set(snap["counters"])
         for name in snap["spans"]:
@@ -173,6 +176,27 @@ def test_cycle_counters_match_the_cycle_steps(runs):
     _, results, snap = runs["fleet"]["traced"]
     assert host.counter(snap, "steps.analytic") == sum(
         r.mode == "analytic" for r in results)
+
+
+def test_ready_evals_count_one_readiness_per_key(runs):
+    # an FR-FCFS pick evaluates readiness once per (bank, direction, SID)
+    # of its queued row hits: a 64-deep window of a striped stream holds
+    # about 16 such keys, against 64 transactions
+    host.enable()
+    sched.make_channel_sim("hbm4", queue_depth=64).run(
+        sched.sequential_read_txns_hbm4(1 << 15))
+    snap = host.disable()
+    evals = host.counter(snap, "cycle.ready_evals")
+    assert 0 < evals <= 17 * host.counter(snap, "cycle.iters")
+    snap = runs["hbm4"]["traced"][2]
+    assert 0 < host.counter(snap, "cycle.ready_evals") <= \
+        64 * host.counter(snap, "cycle.iters")
+    # RoMe's row policy makes no readiness pick and reports nothing
+    host.enable()
+    sched.make_channel_sim("rome").run(
+        sched.sequential_read_txns_rome(1 << 20))
+    assert "cycle.ready_evals" not in host.disable()["counters"]
+    assert host.counter(runs["replay"]["traced"][2], "cycle.ready_evals") == 0
 
 
 def test_annotations_open_and_close_in_nesting_order():

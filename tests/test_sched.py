@@ -166,3 +166,91 @@ def test_duplicate_txns_complete_once_under_all_policies():
         r = sim.run(txns)
         assert np.all(r.finish_ns > 0)
         assert len(np.unique(r.finish_ns)) == 3
+
+
+# ---------------------------------------------------------------------------
+# FR-FCFS column pick: one readiness evaluation per (bank, direction, SID)
+# ---------------------------------------------------------------------------
+
+FRFCFS_KINDS = ("hbm4", "hbm4_closed", "hbm4_writedrain", "hbm4_sidgroup")
+PICK_SEEDS = (0, 1, 2, 3)
+
+
+def _per_txn_pick(pol, window, now):
+    """The FR-FCFS column pick that evaluates ``col_ready`` for every
+    queued row hit, same-SID grouping included: the reference the keyed
+    pick must reproduce exactly."""
+    def earliest(txns):
+        best = best_t = None
+        for tx in txns:
+            b = pol.banks[tx.bank]
+            if b.open_row == tx.row and b.t_act <= 1e17:
+                r = pol.col_ready(tx.bank, b, tx.is_write, tx.sid,
+                                  tx.arrival_ns)
+                if best_t is None or r < best_t - 1e-12:
+                    best, best_t = tx, r
+        return best, best_t
+
+    best = best_t = None
+    for group in pol._column_groups(window, now):
+        best, best_t = earliest(group)
+        if best is not None:
+            break
+    if best is None or not isinstance(pol, sched.HBM4SIDGroupPolicy):
+        return best, best_t
+    pc = pol._pc(best.bank)
+    cur = pol.pc_cur_sid[pc]
+    if cur < 0 or best.sid == cur:
+        return best, best_t
+    same, same_t = earliest([tx for tx in window if tx.sid == cur
+                             and pol._pc(tx.bank) == pc])
+    if same is not None and \
+            same_t <= best_t + pol.t.tCCDR - pol.t.tCCDS + 1e-12:
+        return same, same_t
+    return best, best_t
+
+
+def _mixed_trace(seed: int, n: int = 1200) -> list:
+    """Runs of 1-8 column accesses to one (bank, row, direction, SID)
+    over all 128 banks and 3 rows per bank (row conflicts), 30 % reads,
+    2 SIDs; dense arrivals with ties and occasional idle gaps."""
+    rng = np.random.default_rng(seed)
+    txns, t = [], 0.0
+    while len(txns) < n:
+        bank, row = int(rng.integers(128)), int(rng.integers(3))
+        is_write, sid = bool(rng.random() < 0.7), int(rng.integers(2))
+        for col in range(int(rng.integers(1, 9))):
+            gap = rng.random()
+            t += 600.0 if gap < 0.01 else (0.0 if gap < 0.3 else gap)
+            txns.append(eng.Txn(t, bank=bank, row=row, col=col,
+                                is_write=is_write, sid=sid))
+    return txns[:n]
+
+
+def _pick_cases(seed: int) -> list:
+    facade = [(label, kw, txns) for label, kind, kw, txns
+              in sched.facade_trace_suite() if kind.startswith("hbm4")]
+    return facade[seed::len(PICK_SEEDS)] + [
+        (f"mixed_qd{qd}", {"queue_depth": qd}, _mixed_trace(seed))
+        for qd in (2, 64)]
+
+
+@pytest.mark.parametrize("seed", PICK_SEEDS)
+@pytest.mark.parametrize("kind", FRFCFS_KINDS)
+def test_keyed_column_pick_is_bit_identical_to_per_txn_pick(kind, seed):
+    for label, kw, txns in _pick_cases(seed):
+        runs = []
+        for keyed in (True, False):
+            sim = sched.make_channel_sim(kind, emit_trace=True,
+                                         sample_window_ns=250.0, **kw)
+            if not keyed:
+                pol = sim.policy
+                pol._pick_column = lambda w, now, pol=pol: \
+                    _per_txn_pick(pol, w, now)
+            runs.append((sim.run(txns), sim.policy.ready_evals))
+        (new, evals), (ref, ref_evals) = runs
+        assert new.finish_ns.tobytes() == ref.finish_ns.tobytes(), label
+        assert new.cmd_counts == ref.cmd_counts, label
+        assert new.trace == ref.trace, label
+        assert new.samples == ref.samples, label
+        assert ref_evals == 0 < evals, label
