@@ -14,26 +14,16 @@ from functools import partial
 from .traffic import Feed, draw
 
 
-def kv_pool_geometry(kv: dict) -> dict:
-    """The configuration's KV cache as the program's page-pool arguments:
-    the per-token latent split evenly over the recorder's pools."""
-    dims = kv["kv_lora_rank"] + kv["qk_rope_head_dim"]
-    if dims % kv["pools"]:
-        raise ValueError(f"{dims} latent values do not split over "
-                         f"{kv['pools']} pools")
-    return {"n_kv_heads": 1, "head_dim": dims // kv["pools"],
-            "rows_per_page": int(kv["rows_per_page"])}
-
-
 @contextmanager
-def kv_geometry(kv: dict):
-    """Build the program's KV page pools at the configuration's geometry:
-    the builders take the pool factory's defaults, so the factory is
-    bound to the configuration's arguments while they run."""
+def kv_geometry(geometry: dict):
+    """Build the program's KV page pools at the configuration's geometry
+    (its model module's ``pool_geometry``): the builders take the pool
+    factory's defaults, so the factory is bound to these arguments while
+    they run."""
     import repro.serve.cluster.sim as cluster
     import repro.serve.replay.recorder as recorder
     orig = recorder.make_kv_cache
-    bound = partial(orig, **kv_pool_geometry(kv))
+    bound = partial(orig, **geometry)
     recorder.make_kv_cache = cluster.make_kv_cache = bound
     try:
         yield
@@ -41,10 +31,10 @@ def kv_geometry(kv: dict):
         recorder.make_kv_cache = cluster.make_kv_cache = orig
 
 
-def build(config: dict, traffic: dict, seed: int):
+def build(config: dict, traffic: dict, seed: int, equations):
     """``(sim, feed, system)``: the object whose ``run()`` the window
     drives, the arrival feed handed to it, and its pricing
-    ``SystemSim``."""
+    ``SystemSim``. ``equations`` is the configuration's model module."""
     from repro.configs.paper_workloads import ServingMix
     from repro.serve.replay.arrivals import RequestSpec
 
@@ -58,7 +48,7 @@ def build(config: dict, traffic: dict, seed: int):
                   scale=float(traffic["step_scale"]),
                   sim_mode=traffic["sim_mode"], n_requests=1)
     engine = traffic["engine"]
-    with kv_geometry(config["kv"]):
+    with kv_geometry(equations.pool_geometry(config)):
         sim, system = _construct(engine, config, traffic, feed, common)
     if engine == "replay" and int(config["workers"]) != 1:
         raise ValueError("ReplayEngine prices serially; workers must be 1")

@@ -2,9 +2,11 @@
 
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric lives in a file of its own, found by name:
-``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json`` and
-``bench/metrics/<metric>.py``. Adding a cell or a metric therefore means
-adding files and ``BENCHMARK.json`` entries, never editing the harness.
+``bench/configs/<config>.json``, ``bench/traffic/<traffic>.json``,
+``bench/metrics/<metric>.py``, and ``bench/models/<workload>.py``, the
+layer equations of the configuration's ``"workload"``. Adding a cell, a
+metric or a model therefore means adding files and ``BENCHMARK.json``
+entries, never editing the harness.
 """
 from __future__ import annotations
 
@@ -13,12 +15,15 @@ import json
 import re
 from dataclasses import dataclass, field
 from pathlib import Path
+from types import ModuleType
 
 BENCH_DIR = Path(__file__).resolve().parent.parent
 ROOT = BENCH_DIR.parent
 
 NAME_RE = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}")
 UNIT_RE = re.compile(r"[A-Za-z0-9_/%.\-]{1,16}")
+#: What every ``bench/models/<workload>.py`` defines.
+EQUATIONS = ("weight_bytes", "request_bytes", "pool_geometry")
 
 
 class ManifestError(ValueError):
@@ -62,6 +67,7 @@ class Cell:
     end_to_end: tuple
     per_layer: tuple
     readers: dict = field(default_factory=dict)
+    equations: ModuleType | None = None
 
 
 def _metric(entry: dict, per_layer: bool) -> Metric:
@@ -83,19 +89,33 @@ def load_json(path: Path) -> dict:
         return json.load(f)
 
 
-def load_reader(name: str, bench_dir: Path = BENCH_DIR):
-    """The per-layer metric reader ``bench/metrics/<name>.py``."""
-    path = bench_dir / "metrics" / f"{check_name(name, 'metric')}.py"
+def _load_module(path: Path, what: str, attrs: tuple):
     if not path.is_file():
-        raise ManifestError(f"no reader {path} for per-layer metric {name}")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
+        raise ManifestError(f"no {path} for {what}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{path.parent.name}_{path.stem}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    for attr in ("LAYER", "MOVES", "read"):
+    for attr in attrs:
         if not hasattr(mod, attr):
             raise ManifestError(f"{path} defines no {attr}")
     return mod
+
+
+def load_reader(name: str, bench_dir: Path = BENCH_DIR):
+    """The per-layer metric reader ``bench/metrics/<name>.py``."""
+    path = bench_dir / "metrics" / f"{check_name(name, 'metric')}.py"
+    return _load_module(path, f"per-layer metric {name}",
+                        ("LAYER", "MOVES", "read"))
+
+
+def load_equations(workload: str, bench_dir: Path = BENCH_DIR):
+    """The layer equations ``bench/models/<workload>.py`` of a
+    configuration's ``"workload"`` (see ``bench/models/deepseek-v3.py``
+    for the three functions and their contract)."""
+    path = bench_dir / "models" / f"{check_name(workload, 'workload')}.py"
+    return _load_module(path, f"the layer equations of {workload}",
+                        EQUATIONS)
 
 
 class Manifest:
@@ -152,4 +172,6 @@ class Manifest:
                     config=config, traffic=traffic,
                     end_to_end=tuple(m for m in self.end_to_end
                                      if m.applies_to(name)),
-                    per_layer=per_layer, readers=readers)
+                    per_layer=per_layer, readers=readers,
+                    equations=load_equations(config.get("workload"),
+                                             self.bench_dir))

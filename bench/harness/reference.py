@@ -2,20 +2,20 @@
 
 It imports nothing of the program. It reads the program's answers (the
 logged steps, the sampled steps' extent streams, transactions and
-finish times) and holds them against what the configuration file
-states: the layer equations and KV geometry (recorder bytes), the
-stripe map (census and transactions), the controller and its timing
-tables (:mod:`.channel`, cycle steps) and the channels' peak pin
-bandwidth (analytic steps). The numbers compared, each an exact count
+finish times) and holds them against what the configuration states:
+its model's layer equations (recorder bytes: the module
+``bench/models/<workload>.py``, which a cell carries as
+``equations``), the stripe map (census and transactions), the
+controller and its timing tables (:mod:`.channel`, cycle steps) and the
+channels' peak pin bandwidth (analytic steps). The numbers compared, each an exact count
 with its limit in ``bench/checks.json``:
 
 ``bytes_bad``
     Logged steps or sampled (step, request) byte totals that disagree
     with the request lengths: admission out of arrival order, more
     decoders than slots, a request finishing after other than its
-    output length, weight bytes other than the layer equations give, KV
-    reads other than whole pages of the request's context, KV appends
-    other than one token.
+    output length, weight bytes or per-request KV reads and writes
+    other than the model's layer equations give.
 ``census_bad``
     Sampled steps whose per-channel bytes (whole stripe units) differ
     from the reference's stripe census.
@@ -41,65 +41,26 @@ floor, for analytic steps) and the clock they advance.
 """
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import numpy as np
 
 from . import channel
 
-ROW = 4096
 #: Transactions of sampled cycle steps the channel model schedules in a
 #: run: about 6 s of host time on HBM4 decode's 18,886-transaction steps.
 SCHED_TXNS = 100_000
 
 
-# ---------------------------------------------------------------------------
-# Layer equations: the scaled weight slice of one decode step
-# ---------------------------------------------------------------------------
+class Request(NamedTuple):
+    """One decoding request in one step, as a model module's
+    ``request_bytes`` gets it."""
 
-def weight_slice_bytes(model: dict, n_ops: int, scale: float) -> int:
-    """Read bytes of the first ``n_ops`` decode ops' weights on one
-    device (batch 1), each extent scaled and floored at one row."""
-    m = model
-    d, hd = m["d_model"], m["head_dim"]
-    bpp, ndev = m["bytes_per_param"], m["n_devices"]
-    if m["mla_kv_lora"]:
-        attn = (d * m["mla_q_lora"]
-                + m["mla_q_lora"] * m["n_heads"] * (hd + m["mla_rope_dim"])
-                + d * (m["mla_kv_lora"] + m["mla_rope_dim"])
-                + m["mla_kv_lora"] * m["n_heads"] * 2 * hd
-                + m["n_heads"] * hd * d) * bpp
-    else:
-        tp = m["attn_tp"]
-        attn = (d * m["n_heads"] * hd // tp
-                + 2 * d * m["n_kv_heads"] * hd // tp
-                + m["n_heads"] * hd * d // tp) * bpp
-    moe = m["n_experts"] > 0
-    extents = []
-    for layer in range(m["n_layers"]):
-        extents.append([attn])
-        if moe and layer >= m["n_dense_layers"]:
-            expert = 3 * d * m["d_ff"] * bpp
-            e_dev = m["n_experts"] // m["moe_ep"]
-            active = e_dev * (1.0 - (1.0 - m["top_k"] / m["n_experts"]))
-            ffn = [expert] * max(1, round(active))
-            if m["n_shared_experts"]:
-                ffn.append(m["n_shared_experts"] * expert)
-            extents.append(ffn)
-        elif moe:
-            extents.append([3 * d * m["dense_d_ff"] // ndev * bpp])
-        else:
-            extents.append([3 * d * m["d_ff"] // ndev * bpp])
-        if len(extents) >= n_ops:
-            break
-    return sum(max(ROW, int(n * scale))
-               for op in extents[:n_ops] for n in op if n > 0)
-
-
-def kv_page(kv: dict) -> tuple[int, int, int]:
-    """``(pools, bytes per token per pool, tokens per page)``."""
-    per_tok = ((kv["kv_lora_rank"] + kv["qk_rope_head_dim"]) // kv["pools"]
-               * kv["itemsize"])
-    return (kv["pools"], per_tok,
-            kv["rows_per_page"] * kv["row_bytes"] // per_tok)
+    rid: int
+    #: prompt plus the tokens decoded before this step
+    context: int
+    #: the run's ``--seed``
+    seed: int
 
 
 # ---------------------------------------------------------------------------
@@ -177,18 +138,17 @@ def _modelled(checked: dict, budget: int) -> set:
 
 
 def check(config: dict, traffic: dict, reqs, log: list, steps: list,
-          answers: str = "program") -> dict[str, float]:
-    """Readings of every number compared (module docstring)."""
+          equations, seed: int, answers: str = "program") -> dict[str, float]:
+    """Readings of every number compared (module docstring);
+    ``equations`` is the configuration's model module, ``seed`` the
+    run's."""
     if answers not in ("program", "control"):
         raise ValueError(answers)
     if traffic.get("prefill_chunk_tokens") or traffic.get("warm"):
         raise NotImplementedError(
             "the reference covers legacy prefill under reset steps only")
     mem = dict(config["memory"], n_channels=config["n_channels"])
-    pools, per_tok, page_tokens = kv_page(config["kv"])
-    page_bytes = page_tokens * per_tok
-    weights = weight_slice_bytes(config["model"], int(traffic["n_ops"]),
-                                 float(traffic["step_scale"]))
+    weights = int(equations.weight_bytes(config, traffic))
     n_slots = int(traffic["n_slots"])
     checked = {i: (replica, st, res) for i, replica, st, res in steps}
 
@@ -257,9 +217,10 @@ def check(config: dict, traffic: dict, reqs, log: list, steps: list,
         if len(active) > n_slots or not set(active) <= cur:
             bytes_bad += 1
         if k in checked:
-            expect[k] = {rid: (pools * -(-int(prompt[rid] + done[rid])
-                                         // page_tokens) * page_bytes,
-                               pools * per_tok) for rid in active}
+            expect[k] = {rid: equations.request_bytes(
+                config, traffic,
+                Request(rid, int(prompt[rid] + done[rid]), seed))
+                for rid in active}
         for rid in active:
             done[rid] += 1
         for rid in finished:

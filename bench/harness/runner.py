@@ -71,7 +71,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
             else nullcontext())
     try:
         with span:
-            sim, feed, system = cells.build(config, traffic, seed)
+            sim, feed, system = cells.build(config, traffic, seed,
+                                            cell.equations)
             fleet = traffic["engine"] == "fleet"
             win = Window(seconds, traffic["warmup_steps"],
                          traffic["sample_steps"], seed, feed, fleet,
@@ -113,14 +114,15 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, t_start: float,
     del sim, system, feed, win
     gc.collect()
     t_ref = time.perf_counter()
-    readings = reference.check(config, traffic, reqs, log, steps)
+    readings = reference.check(config, traffic, reqs, log, steps,
+                               cell.equations, seed)
     checks = {k: {"value": v, "limit": limits[k]}
               for k, v in readings.items()}
     failed = count_failed(readings, limits)
     ctrl = None
     if control == "float32":
         low = reference.check(config, traffic, reqs, log, steps,
-                              answers="control")
+                              cell.equations, seed, answers="control")
         n = count_failed(low, limits)
         ctrl = {"readings": low, "failed": n, "correct": n == 0}
     elif control is not None:

@@ -10,9 +10,10 @@ SECONDS = 1.5
 
 
 def run(cell_name: str, seed: int = 2 ** 31 + 5, control=None,
-        seconds: float = SECONDS, size_seed: int | None = None) -> dict:
+        seconds: float = SECONDS, size_seed: int | None = None,
+        manifest: Manifest | None = None) -> dict:
     jax = device.init_jax()
-    cell = Manifest.load().cell(cell_name)
+    cell = (manifest or Manifest.load()).cell(cell_name)
     if size_seed is not None:
         # another request set than the traffic file's
         arrivals = dict(cell.traffic["arrivals"], size_seed=size_seed)
