@@ -39,6 +39,8 @@ DECLARED: dict[str, tuple[str, str]] = {
     "recorder.kv_streams": (SPAN, "recorder + batcher"),
     "recorder.interleave": (SPAN, "recorder + batcher"),
     "recorder.records": (COUNTER, "recorder + batcher"),
+    "recorder.state_streams": (SPAN, "recurrent state"),
+    "recorder.state_bytes": (COUNTER, "recurrent state"),
     "census": (SPAN, "extent census"),
     "pricing": (SPAN, "analytic pricing"),
     "steps.analytic": (COUNTER, "analytic pricing"),

@@ -55,7 +55,8 @@ from ...core.system_sim import SystemSim
 from ...obs import host
 from ..replay.arrivals import ArrivalProcess, RequestSpec
 from ..replay.recorder import (KV_BASE_ADDR, ServeTraceRecorder,
-                               make_kv_cache, weight_step_stream)
+                               make_kv_cache, make_state_pool,
+                               weight_step_stream)
 from .router import Router, make_router
 
 #: replica_of sentinel values
@@ -113,14 +114,15 @@ class Replica:
     """One serving replica: recorder + routed queue + private clock."""
 
     def __init__(self, index: int, cache, weight_stream, kv_offset_ns,
-                 kv_base_addr, n_slots: int):
+                 kv_base_addr, n_slots: int, state=None):
         self.index = index
         self.n_slots = n_slots
         self.queue = RoutedQueue()
         self.rec = ServeTraceRecorder(self.queue, cache,
                                       weight_stream=weight_stream,
                                       kv_offset_ns=kv_offset_ns,
-                                      kv_base_addr=kv_base_addr)
+                                      kv_base_addr=kv_base_addr,
+                                      state=state)
         self.clock = 0.0
         self.ema_step_ns = 0.0
         #: worst-case KV pages of every routed-but-not-finished request —
@@ -332,13 +334,14 @@ class ClusterSim:
                 "round into per-replica sessions. For warm (prefill-"
                 "aware) studies run a per-cube ReplayEngine(warm=True) — "
                 "see docs/serve_replay.md.")
-        from ...configs.paper_workloads import PAPER_WORKLOADS, SERVING_MIXES
+        from ...configs.paper_workloads import (SERVING_MIXES,
+                                                SERVING_WORKLOADS)
         from ...core.sched.registry import policy_spec
         from ...perfmodel.accelerator import scaled_accelerator
         from ...trace.layergraph import ROW
 
         spec = policy_spec(policy)
-        w = PAPER_WORKLOADS[workload]
+        w = SERVING_WORKLOADS[workload]
         mix = SERVING_MIXES[workload] if mix is None else mix
         acc = scaled_accelerator(spec.family, n_channels=n_channels)
         ws, chain_ns = weight_step_stream(w, acc, n_ops=n_ops, scale=scale)
@@ -349,10 +352,12 @@ class ClusterSim:
         self.arrivals = ArrivalProcess(kind, rate_rps, n_requests, mix=mix,
                                        length_scale=length_scale, seed=seed,
                                        **arrival_kw)
-        self.replicas = [
-            Replica(i, make_kv_cache(n_slots, max_tokens), ws, chain_ns,
-                    kv_base, n_slots)
-            for i in range(n_replicas)]
+        self.replicas = []
+        for i in range(n_replicas):
+            cache = make_kv_cache(n_slots, max_tokens)
+            self.replicas.append(Replica(
+                i, cache, ws, chain_ns, kv_base, n_slots,
+                state=make_state_pool(w, n_slots, cache, kv_base)))
         self.router: Router = make_router(router)
         self.system: SystemSim = spec.system_sim(n_channels=n_channels,
                                                  mode=sim_mode)

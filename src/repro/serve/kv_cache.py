@@ -175,6 +175,10 @@ class RowPagedKVCache:
         """Byte span of one pool (K or V)."""
         return self.n_pages * self.page_bytes
 
+    def end_addr(self, base_addr: int = 0) -> int:
+        """First byte past both pools laid out from ``base_addr``."""
+        return base_addr + 2 * self.pool_span_bytes
+
     def page_addr(self, page_id: int, base_addr: int = 0,
                   pool: str = "k") -> int:
         if pool not in ("k", "v"):

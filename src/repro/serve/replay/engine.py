@@ -170,6 +170,11 @@ class ReplayEngine:
                  overhead_ns: float = 0.0, keep_traces: bool = False,
                  max_steps: int = 100_000, warm: bool = False,
                  collector=None):
+        if warm and recorder.state is not None:
+            raise NotImplementedError(
+                "warm replay of a workload with recurrent state: no state "
+                "prefill traffic is modelled, so no warm (prefill) study "
+                "can hold it")
         self.recorder = recorder
         self.system = system
         self.overhead_ns = overhead_ns
@@ -300,23 +305,25 @@ def build_replay(workload: str = "deepseek-v3",
     :class:`~.recorder.ServeTraceRecorder`), ``prefill_overlap``
     selects packing-prefetch vs prefill-priority stalls, and ``warm``
     prices the replay as one warm cross-step session — the recommended
-    trio for prefill studies (benchmarks/serve_trace.py).
+    trio for prefill studies (benchmarks/serve_trace.py). A workload with
+    recurrent state (Kimi-Linear) takes neither: no state prefill
+    traffic is modelled, so both raise ``NotImplementedError``.
 
     ``collector`` threads a :class:`repro.obs.ObsCollector` into the
     engine; a collector carrying a :class:`~repro.obs.MetricsProbe` also
     attaches it to the SystemSim, turning on windowed channel telemetry
     for every cycle-priced step (examples/obs_trace.py).
     """
-    from ...configs.paper_workloads import PAPER_WORKLOADS, SERVING_MIXES
+    from ...configs.paper_workloads import SERVING_MIXES, SERVING_WORKLOADS
     from ...core.sched.registry import policy_spec
     from ...perfmodel.accelerator import scaled_accelerator
     from ...trace.layergraph import ROW
     from .arrivals import ArrivalProcess
     from .recorder import (KV_BASE_ADDR, ServeTraceRecorder, make_kv_cache,
-                           weight_step_stream)
+                           make_state_pool, weight_step_stream)
 
     spec = policy_spec(policy)
-    w = PAPER_WORKLOADS[workload]
+    w = SERVING_WORKLOADS[workload]
     mix = SERVING_MIXES[workload] if mix is None else mix
     acc = scaled_accelerator(spec.family, n_channels=n_channels)
     ws, chain_ns = weight_step_stream(w, acc, n_ops=n_ops, scale=scale)
@@ -334,7 +341,9 @@ def build_replay(workload: str = "deepseek-v3",
                                   kv_offset_ns=chain_ns,
                                   kv_base_addr=kv_base,
                                   prefill_chunk_tokens=prefill_chunk_tokens,
-                                  prefill_overlap=prefill_overlap)
+                                  prefill_overlap=prefill_overlap,
+                                  state=make_state_pool(w, n_slots, cache,
+                                                        kv_base))
     system = spec.system_sim(n_channels=n_channels, mode=sim_mode)
     engine = ReplayEngine(recorder, system, overhead_ns=overhead_ns,
                           keep_traces=keep_traces, warm=warm,

@@ -14,6 +14,11 @@ decode step at a time, and emits each step as one multi-tenant
   per active slot, retagged with the request id;
 * **KV appends** — one :meth:`~RowPagedKVCache.append_stream` per
   active slot (the decoded token's K/V write), retagged likewise;
+* **recurrent state** (a workload with linear-attention layers) — per
+  active slot, every state of one period of the layer pattern read
+  whole after the KV reads and rewritten in place after the KV append
+  (:class:`~repro.serve.state_pool.RecurrentStatePool`), tagged with the
+  request id;
 * **prefill extents** (``prefill_chunk_tokens`` set) — per prefill
   chunk, the chunk-attention *prefix read* (whole-page reads of the
   context prefilled so far) plus the chunk's prompt-scale K/V appends
@@ -47,6 +52,7 @@ from ...workloads import (ExtentStream, from_layer_ops, layer_ops_span_ns,
                           scale_layer_ops)
 from ..batching import ContinuousBatcher, Request
 from ..kv_cache import RowPagedKVCache, tokens_per_row
+from ..state_pool import RecurrentStatePool
 from .arrivals import ArrivalProcess, RequestSpec
 
 #: Weight records are tagged ``WEIGHT_STREAM_BASE - op_index`` — negative,
@@ -111,6 +117,18 @@ def make_kv_cache(n_slots: int, max_seq_tokens: int,
         max_pages_per_seq=pages_per_seq, dtype=dtype)
 
 
+def make_state_pool(w, n_slots: int, cache: RowPagedKVCache,
+                    kv_base_addr: int) -> RecurrentStatePool | None:
+    """The recurrent states of one period of ``w``'s layer pattern (a
+    block per slot and KDA layer), at the first row past ``cache``'s
+    pools; None for a workload without linear attention."""
+    n_layers = w.period_kinds.count("kda")
+    if not n_layers:
+        return None
+    return RecurrentStatePool(n_slots, n_layers, w.kda_state_bytes,
+                              cache.end_addr(kv_base_addr))
+
+
 @dataclass(frozen=True)
 class StepTrace:
     """One recorded step (decode, prefill, or both)."""
@@ -154,7 +172,8 @@ class ServeTraceRecorder:
                  kv_offset_ns: float = 0.0,
                  kv_base_addr: int = KV_BASE_ADDR,
                  prefill_chunk_tokens: int | None = None,
-                 prefill_overlap: bool = True):
+                 prefill_overlap: bool = True,
+                 state: RecurrentStatePool | None = None):
         n_slots = cache.max_seqs if n_slots is None else n_slots
         if n_slots > cache.max_seqs:
             raise ValueError(
@@ -167,12 +186,25 @@ class ServeTraceRecorder:
                 f"weight slice spans to {w_end} B, past kv_base_addr="
                 f"{kv_base_addr}; shrink the slice scale or raise the "
                 f"KV base")
+        if state is not None:
+            if state.base_addr < cache.end_addr(kv_base_addr):
+                raise ValueError(
+                    f"state pool at {state.base_addr} B overlaps the KV "
+                    f"pools, which end at {cache.end_addr(kv_base_addr)} B")
+            if state.n_slots < n_slots:
+                raise ValueError(f"state pool holds {state.n_slots} slots, "
+                                 f"fewer than n_slots={n_slots}")
+            if prefill_chunk_tokens is not None:
+                raise NotImplementedError(
+                    "chunked prefill of a workload with recurrent state: "
+                    "no state prefill traffic is modelled")
         self.arrivals = arrivals
         self.cache = cache
         self.weight_stream = weight_stream
         self.kv_offset_ns = kv_offset_ns
         self.kv_base_addr = kv_base_addr
         self.prefill_overlap = prefill_overlap
+        self.state = state
         self.batcher = ContinuousBatcher(
             n_slots, admit=self._admit,
             prefill_chunk_tokens=prefill_chunk_tokens)
@@ -238,7 +270,9 @@ class ServeTraceRecorder:
         is active (the caller should jump the clock to the next arrival).
         Per decoding slot the emitted order is read-then-append: the
         attention gather sees the pre-append sequence length, the decoded
-        token's K/V write lands after it. All slots' KV groups arrive at
+        token's K/V write lands after it. With recurrent state the
+        slot's state reads follow its KV reads, and its in-place state
+        writes follow the append. All slots' KV groups arrive at
         ``now + kv_offset_ns`` — with the offset set to the weight
         chain's span (:func:`weight_step_stream`), the gather behaves
         like the op following the slice; tenants still contend with each
@@ -262,6 +296,8 @@ class ServeTraceRecorder:
             # starts the sequence empty — its pages arrive chunk by
             # chunk through append_chunk_stream.
             self.cache.alloc_seq(slot, 0 if chunked else req.prompt_len)
+            if self.state is not None:
+                self.state.alloc(slot)
             admitted.append(req.rid)
         active = [(slot, req) for slot, req in enumerate(self.batcher.active)
                   if req is not None]
@@ -281,12 +317,18 @@ class ServeTraceRecorder:
                 if prefill_only or not req.prefill_done:
                     continue
                 decoding.append(req.rid)
-                streams.append(
-                    self.cache.read_stream(slot, self.kv_base_addr,
-                                           arrival_ns=kv_ns).retagged(req.rid)
-                    + self.cache.append_stream(slot, self.kv_base_addr,
-                                               arrival_ns=kv_ns)
-                    .retagged(req.rid))
+                reads = self.cache.read_stream(
+                    slot, self.kv_base_addr,
+                    arrival_ns=kv_ns).retagged(req.rid)
+                append = self.cache.append_stream(
+                    slot, self.kv_base_addr,
+                    arrival_ns=kv_ns).retagged(req.rid)
+                if self.state is None:
+                    streams.append(reads + append)
+                else:
+                    s_reads, s_writes = self.state.step_streams(slot, kv_ns,
+                                                                req.rid)
+                    streams.append(reads + s_reads + append + s_writes)
             for slot, req, n in pack:
                 # Chunk attention reads the context prefilled so far (empty
                 # on the first chunk), then the chunk's K/V lands as
@@ -307,6 +349,8 @@ class ServeTraceRecorder:
         prefill_done = self.batcher.apply_prefill(pack)
         for req in finished:
             self.cache.free_seq(slot_of[req.rid])
+            if self.state is not None:
+                self.state.free(slot_of[req.rid])
             self._committed_pages -= self._worst_pages.pop(req.rid)
         if not decoding:
             kind = "prefill"       # decode stalled or nothing decodable
@@ -331,5 +375,5 @@ class ServeTraceRecorder:
 
 
 __all__ = ["ServeTraceRecorder", "StepTrace", "weight_ops",
-           "weight_step_stream", "make_kv_cache",
+           "weight_step_stream", "make_kv_cache", "make_state_pool",
            "WEIGHT_STREAM_BASE", "KV_BASE_ADDR"]

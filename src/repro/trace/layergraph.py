@@ -58,6 +58,56 @@ def _expected_active_experts(n_experts: int, top_k: int, tokens: int,
     return experts_per_device * (1.0 - p_unused)
 
 
+def _attention(w: PaperWorkload, kind: str, b_local: int,
+               seq_len: int) -> tuple[int, int, int, float]:
+    """``(weight bytes, cache read bytes, cache write bytes, flops)`` of
+    one attention layer of ``kind`` on one device, for ``b_local``
+    sequences of ``seq_len`` tokens."""
+    d, hd, bpp = w.d_model, w.head_dim, w.bytes_per_param
+    if kind == "mla":               # MLA (DeepSeek): DP attention
+        q_dim = w.n_heads * (hd + w.mla_rope_dim)
+        wq = (d * w.mla_q_lora + w.mla_q_lora * q_dim if w.mla_q_lora
+              else d * q_dim)       # without q-LoRA q projects directly
+        wkv_d = d * (w.mla_kv_lora + w.mla_rope_dim)
+        wkv_u = w.mla_kv_lora * w.n_heads * (2 * hd)
+        wo = w.n_heads * hd * d
+        attn_w = (wq + wkv_d + wkv_u + wo) * bpp
+        kv_per_tok = w.kv_bytes_per_token_per_layer
+        flops = 2.0 * b_local * (attn_w / bpp) \
+            + 2.0 * b_local * seq_len * (w.mla_kv_lora + w.mla_rope_dim) \
+            * (1 + w.n_heads)
+        return (attn_w, b_local * seq_len * kv_per_tok, b_local * kv_per_tok,
+                flops)
+    if kind == "gqa":               # GQA with TP
+        tp = w.attn_tp
+        wq = d * (w.n_heads * hd) // tp
+        wkv = 2 * d * (w.n_kv_heads * hd) // tp
+        wo = (w.n_heads * hd) * d // tp
+        attn_w = (wq + wkv + wo) * bpp
+        kv_per_tok = w.kv_bytes_per_token_per_layer // tp
+        flops = 2.0 * b_local * (attn_w / bpp) \
+            + 4.0 * b_local * seq_len * (w.n_heads // tp) * hd
+        return (attn_w, b_local * seq_len * kv_per_tok, b_local * kv_per_tok,
+                flops)
+    if kind == "kda":               # KDA (Kimi Linear): DP attention
+        width = w.kda_heads * w.kda_head_dim
+        rank = w.kda_gate_rank
+        params = (3 * d * width                     # q, k, v
+                  + width * d                       # o
+                  + 2 * (d * rank + rank * width)   # forget and output gates
+                  + d * w.kda_heads                 # beta
+                  + 3 * width * w.kda_conv)         # depthwise short conv
+        # per head and token, 7 dk*dv flops: decay the state (1), read
+        # it with k (2), the rank-one delta update (2), read it with q
+        # (2); plus the conv's multiply-adds
+        flops = 2.0 * b_local * params + b_local * (
+            7.0 * w.kda_heads * w.kda_head_dim ** 2
+            + 2.0 * 3 * width * w.kda_conv)
+        state = b_local * w.kda_state_bytes
+        return params * bpp, state, state, flops
+    raise ValueError(f"unknown attention kind {kind!r}")
+
+
 # ---------------------------------------------------------------------------
 # Paper workloads (decode step; per device)
 # ---------------------------------------------------------------------------
@@ -67,32 +117,12 @@ def decode_ops(w: PaperWorkload, batch: int, seq_len: int,
     """One decode step on one device. `batch` = global batch size."""
     alloc = RowAllocator()
     ops: list[LayerOp] = []
-    d, hd = w.d_model, w.head_dim
+    d = w.d_model
 
-    # --- attention weights (per device) ------------------------------------
-    tp = w.attn_tp
-    b_local = batch // (n_devices // tp) if tp < n_devices else batch
-    if w.mla_kv_lora:               # MLA (DeepSeek): DP attention
-        wq_d = d * w.mla_q_lora
-        wq_u = w.mla_q_lora * w.n_heads * (hd + w.mla_rope_dim)
-        wkv_d = d * (w.mla_kv_lora + w.mla_rope_dim)
-        wkv_u = w.mla_kv_lora * w.n_heads * (2 * hd)
-        wo = w.n_heads * hd * d
-        attn_w = (wq_d + wq_u + wkv_d + wkv_u + wo) * w.bytes_per_param
-        kv_per_tok = w.kv_bytes_per_token_per_layer
-        kv_read = b_local * seq_len * kv_per_tok
-        attn_flops = 2.0 * b_local * (attn_w / w.bytes_per_param) \
-            + 2.0 * b_local * seq_len * (w.mla_kv_lora + w.mla_rope_dim) \
-            * (1 + w.n_heads)
-    else:                           # GQA with TP
-        wq = d * (w.n_heads * hd) // tp
-        wkv = 2 * d * (w.n_kv_heads * hd) // tp
-        wo = (w.n_heads * hd) * d // tp
-        attn_w = (wq + wkv + wo) * w.bytes_per_param
-        kv_per_tok = w.kv_bytes_per_token_per_layer // tp
-        kv_read = b_local * seq_len * kv_per_tok
-        attn_flops = 2.0 * b_local * (attn_w / w.bytes_per_param) \
-            + 4.0 * b_local * seq_len * (w.n_heads // tp) * hd
+    b_local = batch // (n_devices // w.attn_tp) \
+        if w.attn_tp < n_devices else batch
+    attn = {k: _attention(w, k, b_local, seq_len)
+            for k in {w.attn_kind(i) for i in range(w.n_layers)}}
 
     # --- FFN weights --------------------------------------------------------
     if w.is_moe:
@@ -116,10 +146,15 @@ def decode_ops(w: PaperWorkload, batch: int, seq_len: int,
 
     for layer in range(w.n_layers):
         # attention
+        kind = w.attn_kind(layer)
+        attn_w, cache_read, cache_write, attn_flops = attn[kind]
         extents = [alloc.alloc(attn_w)]
         for s in range(min(b_local, 64)):   # cap extent count; scale below
-            extents.append(alloc.alloc(kv_read // max(1, min(b_local, 64))))
-        wx = walloc(b_local * kv_per_tok, act_bytes, act_bytes)
+            extents.append(alloc.alloc(cache_read // max(1, min(b_local, 64))))
+        if kind == "kda":       # the recurrent state is rewritten in place
+            wx = extents[1:] + walloc(act_bytes, act_bytes)
+        else:
+            wx = walloc(cache_write, act_bytes, act_bytes)
         ops.append(LayerOp(
             name=f"L{layer}.attn", kind="attn",
             flops=attn_flops,

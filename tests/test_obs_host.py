@@ -3,7 +3,8 @@
 Off, the tracer is a shared null context and an empty snapshot; on, it
 accounts self and total time over nested spans, refuses undeclared
 names, never changes a replay's or a fleet's result, and every name its
-table declares is emitted by a small RoMe or HBM4 replay or fleet run.
+table declares is emitted by a small RoMe or HBM4 replay or fleet run
+(the recurrent-state names by a Kimi-Linear replay).
 """
 from __future__ import annotations
 
@@ -18,6 +19,7 @@ REPLAY_KW = dict(policy="rome_qd2", rate_rps=2e5, n_requests=3, seed=0,
                  scale=2 ** -14, length_scale=1 / 32, n_channels=2,
                  sim_mode="cycle", kind="bursty", burst_size=3)
 HBM4_KW = REPLAY_KW | dict(policy="hbm4_frfcfs")
+KIMI_KW = REPLAY_KW | dict(workload="kimi-linear")
 FLEET_KW = dict(policy="rome_qd2", n_replicas=2, n_requests=6,
                 rate_rps=2e5, kind="poisson", seed=0, scale=2 ** -12,
                 sim_mode="hybrid", n_channels=2, length_scale=1 / 32,
@@ -63,8 +65,8 @@ def _run(kind: str, traced: bool):
     steps = Steps()
     if traced:
         host.enable()
-    if kind in ("replay", "hbm4"):
-        kw = REPLAY_KW if kind == "replay" else HBM4_KW
+    if kind in ("replay", "hbm4", "kimi"):
+        kw = {"replay": REPLAY_KW, "hbm4": HBM4_KW, "kimi": KIMI_KW}[kind]
         eng, _ = build_replay(collector=steps, **kw)
         out = eng.run()
     else:
@@ -75,7 +77,7 @@ def _run(kind: str, traced: bool):
 @pytest.fixture(scope="module")
 def runs():
     return {kind: {"bare": _run(kind, False), "traced": _run(kind, True)}
-            for kind in ("replay", "fleet", "hbm4")}
+            for kind in ("replay", "fleet", "hbm4", "kimi")}
 
 
 def test_off_is_a_shared_null_span_and_an_empty_snapshot():
@@ -142,7 +144,7 @@ def test_undeclared_names_raise_when_on():
         host.counter(host.snapshot(), "no.such.counter")
 
 
-@pytest.mark.parametrize("kind", ["replay", "fleet", "hbm4"])
+@pytest.mark.parametrize("kind", ["replay", "fleet", "hbm4", "kimi"])
 def test_tracing_never_changes_a_result(runs, kind):
     bare, bare_steps, off = runs[kind]["bare"]
     traced, traced_steps, on = runs[kind]["traced"]
@@ -154,7 +156,7 @@ def test_tracing_never_changes_a_result(runs, kind):
 
 def test_every_declared_name_is_emitted_and_nothing_else(runs):
     seen = set()
-    for kind in ("replay", "fleet", "hbm4"):
+    for kind in ("replay", "fleet", "hbm4", "kimi"):
         snap = runs[kind]["traced"][2]
         seen |= set(snap["spans"]) | set(snap["counters"])
         for name in snap["spans"]:
@@ -176,6 +178,18 @@ def test_cycle_counters_match_the_cycle_steps(runs):
     _, results, snap = runs["fleet"]["traced"]
     assert host.counter(snap, "steps.analytic") == sum(
         r.mode == "analytic" for r in results)
+
+
+def test_state_counter_matches_the_decoding_slots(runs):
+    # every decoding slot reads and rewrites three KDA states a step
+    out, _, snap = runs["kimi"]["traced"]
+    slot_steps = sum(s.n_active for s in out.steps)
+    assert slot_steps > 0
+    assert host.counter(snap, "recorder.state_bytes") == \
+        slot_steps * 2 * 3 * 2_170_880
+    assert host.span_s(snap, "recorder.state_streams") > 0
+    assert "recorder.state_bytes" not in runs["replay"]["traced"][2][
+        "counters"]
 
 
 def test_ready_evals_count_one_readiness_per_key(runs):
